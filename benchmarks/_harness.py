@@ -8,7 +8,8 @@ not drift per-script:
   repetitions under ``jax.block_until_ready``, reporting the *median* (a
   single descheduled rep skews a mean; a lucky rep skews a min) plus the
   raw samples so a reader can judge the spread;
-* **provenance stamping** -- jax version/backend, the repo git SHA, and
+* **provenance stamping** -- jax version, the device the run used
+  (``platform``, ``device_kind``, device count), the repo git SHA, and
   the exact argv, so a committed ``BENCH_*.json`` can be re-run and
   compared years later.
 
@@ -38,9 +39,13 @@ def provenance(cfg=None, **extra) -> dict:
     """The stamp every committed benchmark artifact carries.  ``cfg`` is
     an optional ``FleetConfig`` (recorded as a dict); ``extra`` lands in
     the stamp verbatim."""
+    device = jax.devices()[0]
     info = {
         "jax_version": jax.__version__,
         "jax_backend": jax.default_backend(),
+        "platform": device.platform,
+        "device_kind": device.device_kind,
+        "device_count": jax.device_count(),
         "git_sha": git_sha(),
         "argv": list(sys.argv),
     }

@@ -6,8 +6,8 @@ more than its share, so all three allocator steps and both service phases
 stay hot) under each (alloc_backend, serve_backend) combination, measures
 steady-state wall clock (compile excluded via a warmup run), and writes
 ``BENCH_alloc_scaling.json`` with windows/sec, wall-clock per simulated
-second, and the VMEM block shapes the kernel dispatchers picked -- the
-"peak shape" record that J=4096 now runs with block_o >= 4, which the old
+second, and the OST block shapes the kernel dispatchers picked -- the
+"peak shape" record that J=4096 runs with 8-row blocks, which the old
 O(J^2) rank matrix could not fit at any block size.
 
 The ``--reference-windows-per-s`` flag embeds an externally measured
@@ -42,8 +42,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import dispatch
-from repro.kernels.adaptbf_alloc import ops as alloc_ops
-from repro.kernels.window_mega import ops as mega_ops
+from repro.launch.compile_cache import use_compile_cache
 from repro.storage import FleetConfig, simulate_fleet
 
 from _harness import blocking, provenance, timeit_steady
@@ -81,17 +80,10 @@ def run_cell(o: int, j: int, alloc_backend: str, serve_backend: str,
     t = timeit_steady(blocking(simulate_fleet, cfg, nodes, rates, volume),
                       reps=reps)
 
-    jp = dispatch.pad_lanes(j)
     sim_seconds = n_windows * window_ticks * cfg.tick_seconds
-    if serve_backend == "mega":
-        # the megakernel blocks the whole round at once: one row-block
-        # policy for serve AND alloc (3 policy-state leaves for adaptbf)
-        serve_block = dispatch.block_rows(
-            o, jp, mega_ops._live_rows(3, window_ticks))
-        alloc_block = serve_block
-    else:
-        alloc_block = dispatch.block_rows(o, jp, alloc_ops._LIVE_ROWS)
-        serve_block = dispatch.block_rows(o, jp, window_ticks + 10)
+    # every kernel blocks OST rows by the same rule; the megakernel blocks
+    # serve AND alloc at once
+    block = dispatch.block_rows(o)
     return {
         "o": o,
         "j": j,
@@ -100,8 +92,8 @@ def run_cell(o: int, j: int, alloc_backend: str, serve_backend: str,
         "n_windows": n_windows,
         "windows_per_s": n_windows / t["wall_s"],
         "wall_per_sim_s": t["wall_s"] / sim_seconds,
-        "alloc_block_o": alloc_block,
-        "serve_block_o": serve_block,
+        "alloc_block_o": block,
+        "serve_block_o": block,
         **t,
     }
 
@@ -173,6 +165,7 @@ def main():
     ap.add_argument("--reference-note", default="",
                     help="provenance of the baseline measurement")
     args = ap.parse_args()
+    use_compile_cache()
     if args.smoke:
         report = sweep(grid_o=(8,), grid_j=(128,), n_windows=2)
     else:

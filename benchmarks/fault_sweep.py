@@ -39,6 +39,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.launch.compile_cache import use_compile_cache
 from repro.storage import (
     FleetConfig,
     faults,
@@ -264,6 +265,7 @@ def main():
     ap.add_argument("--smoke", action="store_true",
                     help="tiny CI grid: calm+severe x 2 seeds at (O=8, J=32)")
     args = ap.parse_args()
+    use_compile_cache()
     if args.policies:
         unknown = set(args.policies) - set(list_policies())
         if unknown:

@@ -38,6 +38,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.launch.compile_cache import use_compile_cache
 from repro.storage import (
     FleetConfig,
     get_scenario,
@@ -223,6 +224,7 @@ def main():
     ap.add_argument("--gen-ost", type=int, default=8)
     ap.add_argument("--gen-jobs", type=int, default=8)
     args = ap.parse_args()
+    use_compile_cache()
     if args.policies:
         unknown = set(args.policies) - set(list_policies())
         if unknown:

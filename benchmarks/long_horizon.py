@@ -27,7 +27,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.launch.compile_cache import use_compile_cache
 from repro.storage import FleetConfig, metrics, simulate_fleet
+
+from _harness import provenance
 
 
 def build_case(o: int, j: int, trace_windows: int, window_ticks: int,
@@ -86,10 +89,7 @@ def run(windows: int, o: int, j: int, trace_windows: int, policy: str,
             "p99_backlog_growth": metrics.streaming_p99_queue(stats),
             "slowdown_mean": float(np.nanmean(slow)),
         },
-        "provenance": {
-            "jax_version": jax.__version__,
-            "jax_backend": jax.default_backend(),
-        },
+        "provenance": provenance(),
     }
 
 
@@ -103,6 +103,7 @@ def main():
     ap.add_argument("--policy", default="adaptbf")
     ap.add_argument("--serve", choices=("scan", "fused"), default="scan")
     args = ap.parse_args()
+    use_compile_cache()
     report = run(args.windows, args.ost, args.jobs, args.trace_windows,
                  args.policy, args.serve)
     text = json.dumps(report, indent=2, default=float)

@@ -40,6 +40,7 @@ import time
 import jax
 import numpy as np
 
+from repro.launch.compile_cache import use_compile_cache
 from repro.storage import (
     FleetConfig,
     FleetService,
@@ -47,6 +48,8 @@ from repro.storage import (
     get_scenario,
     simulate_fleet,
 )
+
+from _harness import provenance
 
 
 def parse_fault_plan(spec, n_windows: int, n_ost: int):
@@ -151,10 +154,7 @@ def run(scenario: str, duration_s: float, policy: str,
         "crash_window": crash_window,
         "modes": modes,
         "all_bitwise": all(m["bitwise_match"] for m in modes),
-        "provenance": {
-            "jax_version": jax.__version__,
-            "jax_backend": jax.default_backend(),
-        },
+        "provenance": provenance(),
     }
 
 
@@ -173,6 +173,7 @@ def main():
     ap.add_argument("--smoke", action="store_true",
                     help="short horizon for CI (duration-s=4)")
     args = ap.parse_args()
+    use_compile_cache()
     if args.smoke:
         args.duration_s = min(args.duration_s, 4.0)
     report = run(args.scenario, args.duration_s, args.policy,

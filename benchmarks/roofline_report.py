@@ -9,20 +9,17 @@ For each serve backend (``scan`` | ``fused`` | ``mega``) and fleet shape
   HBM bytes must cross each backend's fusion boundaries (the whole point
   of the megakernel is shrinking exactly this number) and how many VPU
   flops the round executes;
-* derives the **attainable windows/sec** on the reference part
-  (``repro.launch.roofline`` hardware constants, TPU v5e: 197 TFLOP/s,
-  819 GB/s HBM) as ``1 / max(bytes/BW, flops/peak)`` -- the
+* derives the **attainable windows/sec** on the device it runs on from
+  that device's published peaks (``DEVICE_PEAKS``, keyed by
+  ``device_kind``) as ``1 / max(bytes/BW, flops/peak)`` -- the
   better-of-neither bound a perfectly overlapped kernel cannot beat;
-* **measures the achieved windows/sec** of ``simulate_fleet`` on the
-  local machine (compile excluded, median-of-k steady reps via
+* **measures the achieved windows/sec** of ``simulate_fleet`` on that
+  same device (compile excluded, median-of-k steady reps via
   ``_harness``).
 
-Achieved and attainable live in the same report but are different
-machines off-TPU: the attainable column is the reference-accelerator
-ceiling the traffic model implies, the achieved column is this host.  The
-ratio between *backends* within either column is the portable claim --
-the model says mega moves ~3x fewer bytes per window than scan at
-W=10 ticks, and the measured column shows how much of that survives XLA.
+A device whose kind has no entry in ``DEVICE_PEAKS`` (the CPU among them)
+is refused before anything runs: a roofline share is a time on one chip
+over that chip's own peaks, never a host time over an accelerator's.
 
 Run:  PYTHONPATH=src:benchmarks python benchmarks/roofline_report.py \
           [--out BENCH_roofline.json] [--smoke] [--n-windows 5]
@@ -36,9 +33,11 @@ from __future__ import annotations
 import argparse
 import json
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.roofline import HBM_BW, PEAK_FLOPS
 from repro.storage import FleetConfig, simulate_fleet
 
@@ -46,6 +45,25 @@ from _harness import blocking, provenance, timeit_steady
 
 SHAPES = ((64, 1024), (256, 4096))
 BACKENDS = ("scan", "fused", "mega")
+
+#: Published per-chip peaks, keyed by ``jax.devices()[0].device_kind``.
+DEVICE_PEAKS = {
+    "TPU v5 lite": {"peak_flops": PEAK_FLOPS, "hbm_bw": HBM_BW,
+                    "source": "Google Cloud documentation, TPU v5e: "
+                              "197 TFLOP/s bf16, 819 GB/s HBM"},
+}
+
+
+def device_peaks(device_kind: str) -> dict:
+    """The peaks of ``device_kind``; an unknown kind is an error, never a
+    default."""
+    try:
+        return DEVICE_PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r} (known: "
+            f"{sorted(DEVICE_PEAKS)}); a roofline share needs the chip it "
+            "ran on") from None
 
 #: Elementwise VPU ops per element per tick of the serve loop.  The scan
 #: oracle's ``_serve_tick`` runs ~22 arithmetic passes (issue: 4, phase 1:
@@ -103,12 +121,12 @@ def window_model(backend: str, o: int, j: int, w: int) -> dict:
     }
 
 
-def attainable(model: dict) -> dict:
-    """Reference-part roofline: windows/sec if the only cost were HBM
-    traffic (memory bound) or VPU issue (compute bound), and the binding
-    minimum of the two."""
-    mem_s = model["hbm_bytes_per_window"] / HBM_BW
-    comp_s = model["flops_per_window"] / PEAK_FLOPS
+def attainable(model: dict, peaks: dict) -> dict:
+    """Roofline of the device with ``peaks``: windows/sec if the only cost
+    were HBM traffic (memory bound) or VPU issue (compute bound), and the
+    binding minimum of the two."""
+    mem_s = model["hbm_bytes_per_window"] / peaks["hbm_bw"]
+    comp_s = model["flops_per_window"] / peaks["peak_flops"]
     bound_s = max(mem_s, comp_s)
     return {
         "memory_bound_windows_per_s": 1.0 / mem_s,
@@ -127,7 +145,7 @@ def _case(o: int, j: int, n_windows: int, window_ticks: int, seed: int = 0):
     return nodes, rates, volume
 
 
-def run_cell(o: int, j: int, backend: str, n_windows: int,
+def run_cell(o: int, j: int, backend: str, n_windows: int, peaks: dict,
              window_ticks: int = 10, reps: int = 3) -> dict:
     cfg = FleetConfig(control="adaptbf", serve_backend=backend,
                       window_ticks=window_ticks)
@@ -135,7 +153,7 @@ def run_cell(o: int, j: int, backend: str, n_windows: int,
     t = timeit_steady(blocking(simulate_fleet, cfg, nodes, rates, volume),
                       reps=reps)
     model = window_model(backend, o, j, window_ticks)
-    bound = attainable(model)
+    bound = attainable(model, peaks)
     achieved = n_windows / t["wall_s"]
     return {
         "o": o,
@@ -154,10 +172,12 @@ def run_cell(o: int, j: int, backend: str, n_windows: int,
 
 def sweep(shapes=SHAPES, backends=BACKENDS, n_windows: int = 5,
           window_ticks: int = 10) -> dict:
+    device_kind = jax.devices()[0].device_kind
+    peaks = device_peaks(device_kind)
     cells = []
     for o, j in shapes:
         for backend in backends:
-            cell = run_cell(o, j, backend, n_windows, window_ticks)
+            cell = run_cell(o, j, backend, n_windows, peaks, window_ticks)
             cells.append(cell)
             print(f"  O={o:4d} J={j:5d} {backend:5s}: "
                   f"achieved {cell['achieved_windows_per_s']:8.2f} w/s, "
@@ -187,11 +207,7 @@ def sweep(shapes=SHAPES, backends=BACKENDS, n_windows: int = 5,
             "n_windows": n_windows,
             "window_ticks": window_ticks,
         },
-        "hardware_model": {
-            "peak_flops": PEAK_FLOPS,
-            "hbm_bw": HBM_BW,
-            "source": "repro.launch.roofline (TPU v5e reference part)",
-        },
+        "hardware_model": {"device_kind": device_kind, **peaks},
         "provenance": provenance(),
         "cells": cells,
         "headline": headline,
@@ -205,6 +221,7 @@ def main():
                     help="one tiny (8, 128) cell per backend for CI")
     ap.add_argument("--n-windows", type=int, default=5)
     args = ap.parse_args()
+    use_compile_cache()
     if args.smoke:
         report = sweep(shapes=((8, 128),), n_windows=2)
     else:

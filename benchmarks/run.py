@@ -12,11 +12,15 @@ import time
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 import fleet_sweep
+import jax
 import paper_figures
 import roofline_report
 
+from repro.launch.compile_cache import use_compile_cache
+
 
 def main() -> None:
+    use_compile_cache()
     benches = [
         ("ivd_token_allocation_fig3_4", paper_figures.fig3_4_token_allocation),
         ("ive_redistribution_fig5_6", paper_figures.fig5_6_redistribution),
@@ -43,6 +47,10 @@ def main() -> None:
     print()
     print("## window-engine roofline (small cell; full grid: "
           "benchmarks/roofline_report.py --out BENCH_roofline.json)")
+    kind = jax.devices()[0].device_kind
+    if kind not in roofline_report.DEVICE_PEAKS:
+        print(f"not measured: no published peaks for device kind {kind!r}")
+        return
     roof = roofline_report.sweep(shapes=((8, 128),), n_windows=2)
     print(json.dumps(roof["cells"], indent=2, default=float))
 

@@ -39,6 +39,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.launch.compile_cache import use_compile_cache
 from repro.storage import (
     FleetConfig,
     list_policies,
@@ -156,6 +157,7 @@ def main():
     ap.add_argument("--smoke", action="store_true",
                     help="tiny CI grid: 2 seeds at (O=8, J=64)")
     args = ap.parse_args()
+    use_compile_cache()
     if args.policies:
         unknown = set(args.policies) - set(list_policies())
         if unknown:

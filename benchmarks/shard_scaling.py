@@ -9,10 +9,12 @@ workload (``benchmarks/long_horizon.build_case``) under ``shard_map`` on an
 N-way ``ost`` mesh; the 1-device cell also times the unsharded engine so
 the report shows the layer's overhead at mesh size 1.
 
-On CPU the forced "devices" are host threads -- the sweep is about proving
-the sharded path's scaling *shape* and keeping it benchmarked; on a real
-multi-chip topology the same flag-free code path shards over the actual
-accelerators.
+This is a CPU rehearsal of the mesh logic and never measures the chip:
+the parent imports JAX and starts children, and a chip belongs to one
+process, so every worker runs with ``JAX_PLATFORMS=cpu`` and the forced
+"devices" are host threads.  The sweep proves the sharded path's scaling
+*shape* and the mesh-invariant physics; on chips the sharded engine runs
+in one process that drives every chip (``chip_smoke.py --four-chips``).
 
 Run:  PYTHONPATH=src python benchmarks/shard_scaling.py \
           [--devices 1 2 4 8] [--ost 256] [--jobs 1024] [--windows 60] \
@@ -34,8 +36,10 @@ def worker(ost: int, jobs: int, windows: int, trace_windows: int,
     import jax
     import numpy as np
 
+    from repro.launch.compile_cache import use_compile_cache
     from repro.storage import FleetConfig, simulate_fleet
 
+    use_compile_cache()
     sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
     from _harness import blocking, timeit_steady
     from long_horizon import build_case
@@ -78,7 +82,7 @@ def sweep(args) -> dict:
                 if not f.startswith("--xla_force_host_platform_device_count")]
         env["XLA_FLAGS"] = " ".join(
             kept + [f"--xla_force_host_platform_device_count={n}"])
-        env.setdefault("JAX_PLATFORMS", "cpu")
+        env["JAX_PLATFORMS"] = "cpu"
         cmd = [sys.executable, os.path.abspath(__file__), "--worker",
                "--devices", str(n), "--ost", str(args.ost),
                "--jobs", str(args.jobs), "--windows", str(args.windows),

@@ -40,6 +40,7 @@ import json
 import jax.numpy as jnp
 import numpy as np
 
+from repro.launch.compile_cache import use_compile_cache
 from repro.storage import FleetConfig, simulate_fleet, simulate_tenants
 from repro.storage.scengen import random_fleet
 
@@ -153,6 +154,7 @@ def main():
     ap.add_argument("--smoke", action="store_true",
                     help="tiny CI ladder: F in {1, 8} at W=20")
     args = ap.parse_args()
+    use_compile_cache()
     if args.smoke:
         report = sweep(fleets=(1, 8), n_ost=args.n_ost, n_jobs=8,
                        windows=20, loop_cap=8, reps=2)

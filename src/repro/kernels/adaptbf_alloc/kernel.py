@@ -13,7 +13,7 @@ allocator literally cannot drift apart.
 
 Block sizing: BLOCK_O x J with J padded to a lane multiple (128).  VMEM
 footprint ~ 16 live [BLOCK_O, J] f32 arrays (see dispatch.block_rows); BLOCK_O=8
-holds out to J=16384, where the old [BLOCK_O, J, J] rank matrix forced
+holds out to J=16384 under the raised scoped-VMEM limit, where the old [BLOCK_O, J, J] rank matrix forced
 BLOCK_O=1 by J~1448 and made J=4096 (64 MB) impossible at any block size.
 """
 from __future__ import annotations
@@ -23,6 +23,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.remainder import integerize as _integerize
 
@@ -131,9 +132,11 @@ def _kernel(demand_ref, nodes_ref, record_ref, rem_ref, prev_ref, cap_ref,
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("u_max", "block_o", "interpret"))
+                   static_argnames=("u_max", "block_o", "vmem_limit_bytes",
+                                    "interpret"))
 def fleet_alloc_pallas(demand, nodes, record, remainder, alloc_prev,
                        capacity, *, u_max: float = 64.0, block_o: int = 8,
+                       vmem_limit_bytes: int = None,
                        interpret: bool = False):
     """[O, J] fleet allocation.  capacity: [O].  J should be a multiple of
     128 and O a multiple of block_o (ops.py pads).  Returns
@@ -150,6 +153,8 @@ def fleet_alloc_pallas(demand, nodes, record, remainder, alloc_prev,
         in_specs=[row_spec] * 5 + [cap_spec],
         out_specs=[row_spec] * 3,
         out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=vmem_limit_bytes),
         interpret=interpret,
     )
     args = [x.astype(jnp.float32) for x in

@@ -10,6 +10,7 @@ from repro.kernels.dispatch import block_rows as _block_rows
 from repro.kernels.dispatch import on_tpu as _on_tpu
 from repro.kernels.dispatch import pad_lanes as _pad_lanes
 from repro.kernels.dispatch import pad_to as _pad_to
+from repro.kernels.dispatch import vmem_limit_bytes as _vmem_limit_bytes
 
 # The top-k selection in core/remainder keeps ~16 live [block_o, J] f32
 # arrays (inputs, outputs, selection temporaries) -- O(J) per row, so
@@ -30,12 +31,14 @@ def fleet_alloc(demand, nodes, record, remainder, alloc_prev, capacity,
         interpret = not _on_tpu()
     o, j = demand.shape
     jp = _pad_lanes(j)
-    bo = _block_rows(o, jp, _LIVE_ROWS)
+    bo = _block_rows(o)
     args = [_pad_to(_pad_to(x, jp, 1), bo, 0)
             for x in (demand, nodes, record, remainder, alloc_prev)]
     cap = _pad_to(capacity.reshape(-1), bo, 0)
     alloc, rec, rem = fleet_alloc_pallas(
-        *args, cap, u_max=u_max, block_o=bo, interpret=interpret)
+        *args, cap, u_max=u_max, block_o=bo,
+        vmem_limit_bytes=_vmem_limit_bytes(bo, jp, _LIVE_ROWS),
+        interpret=interpret)
     return alloc[:o, :j], rec[:o, :j], rem[:o, :j]
 
 

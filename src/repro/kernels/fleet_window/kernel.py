@@ -17,8 +17,9 @@ any registered control policy route through the same ``serve_window``
 dispatch, so kernel parity automatically covers every policy.
 
 VMEM footprint ~ (window_ticks + 10) x BLOCK_O x J f32 arrays: the rate
-trace block dominates; BLOCK_O=8 holds through J=8192 at the default
-10-tick window (see dispatch.block_rows, capped at the local row count).
+trace block dominates.  BLOCK_O is one row tile (or the local row count);
+a wide job axis raises the scoped-VMEM limit instead (see
+dispatch.block_rows and dispatch.vmem_limit_bytes).
 """
 from __future__ import annotations
 
@@ -27,6 +28,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.storage.simulator import _serve_tick
 
@@ -40,25 +42,28 @@ def serve_tick_block(queue, vol_left, budget, rate_t, backlog_cap, cap):
     return queue, vol_left, budget, served
 
 
-def serve_window_block(queue, vol_left, budget, rates, backlog_cap, cap):
-    """All ticks of one window, fused: ``rates`` [W, O, J], state [O, J],
-    ``cap`` [O, 1].  Returns (queue, vol_left, served_window).
+def serve_window_block(queue, vol_left, budget, rates_ref, backlog_cap, cap):
+    """All ticks of one window, fused: ``rates_ref`` the [W, O, J] rate
+    block as a Pallas ref, state [O, J], ``cap`` [O, 1].  Returns (queue,
+    vol_left, served_window).
 
-    ``fori_loop`` + dynamic index, the shape Mosaic lowers well; the XLA
-    fallback (ops._serve_window_xla) runs the same per-tick math under a
-    no-stack ``lax.scan``, which XLA:CPU executes ~1.7x faster.  The
-    window-start budget is consumed and discarded; every window re-gates
-    from the fresh allocation.
+    ``fori_loop`` loading one tick's [O, J] slice from the ref per
+    iteration: Mosaic lowers a dynamic index on a ref's leading axis, but
+    not a ``dynamic_slice`` of a loaded value.  The XLA fallback
+    (ops._serve_window_xla) runs the same per-tick math under a no-stack
+    ``lax.scan``, which XLA:CPU executes ~1.7x faster.  The window-start
+    budget is consumed and discarded; every window re-gates from the fresh
+    allocation.
     """
     def tick(t, carry):
         queue, vol_left, budget, acc = carry
-        rate_t = jax.lax.dynamic_index_in_dim(rates, t, 0, keepdims=False)
+        rate_t = rates_ref[t]
         queue, vol_left, budget, served = serve_tick_block(
             queue, vol_left, budget, rate_t, backlog_cap, cap)
         return queue, vol_left, budget, acc + served
 
     queue, vol_left, _, served = jax.lax.fori_loop(
-        0, rates.shape[0], tick,
+        0, rates_ref.shape[0], tick,
         (queue, vol_left, budget, jnp.zeros_like(queue)))
     return queue, vol_left, served
 
@@ -66,16 +71,18 @@ def serve_window_block(queue, vol_left, budget, rates, backlog_cap, cap):
 def _kernel(queue_ref, vol_ref, budget_ref, backlog_ref, cap_ref, rates_ref,
             queue_out, vol_out, served_out):
     queue, vol_left, served = serve_window_block(
-        queue_ref[...], vol_ref[...], budget_ref[...], rates_ref[...],
+        queue_ref[...], vol_ref[...], budget_ref[...], rates_ref,
         backlog_ref[...], cap_ref[...])
     queue_out[...] = queue
     vol_out[...] = vol_left
     served_out[...] = served
 
 
-@functools.partial(jax.jit, static_argnames=("block_o", "interpret"))
+@functools.partial(jax.jit, static_argnames=("block_o", "vmem_limit_bytes",
+                                             "interpret"))
 def fleet_window_pallas(queue, vol_left, budget, backlog_cap, rates,
                         cap_tick, *, block_o: int = 8,
+                        vmem_limit_bytes: int = None,
                         interpret: bool = False):
     """[O, J] window service.  rates: [W, O, J]; cap_tick: [O].  J should be
     a multiple of 128 and O a multiple of block_o (ops.py pads).  Returns
@@ -94,6 +101,8 @@ def fleet_window_pallas(queue, vol_left, budget, backlog_cap, rates,
         in_specs=[row_spec] * 4 + [cap_spec, rates_spec],
         out_specs=[row_spec] * 3,
         out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=vmem_limit_bytes),
         interpret=interpret,
     )
     args = [x.astype(jnp.float32)

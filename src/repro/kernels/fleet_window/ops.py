@@ -12,6 +12,7 @@ from repro.kernels.dispatch import block_rows as _block_rows
 from repro.kernels.dispatch import on_tpu as _on_tpu
 from repro.kernels.dispatch import pad_lanes as _pad_lanes
 from repro.kernels.dispatch import pad_to as _pad_to
+from repro.kernels.dispatch import vmem_limit_bytes as _vmem_limit_bytes
 from repro.kernels.fleet_window import ref
 from repro.kernels.fleet_window.kernel import (
     fleet_window_pallas,
@@ -54,15 +55,17 @@ def fleet_window_serve(queue, vol_left, budget, rates, backlog_cap, cap_tick,
     w = rates.shape[0]
     jp = _pad_lanes(j)
     # the [W, block_o, J] rate-trace block dominates VMEM alongside ~10
-    # [block_o, J] state/temp arrays; keep the sum under ~8 MB (f32), and
-    # never block wider than the (possibly sharded-local) row count
-    bo = _block_rows(o, jp, w + 10)
+    # [block_o, J] state/temp arrays
+    bo = _block_rows(o)
     args = [_pad_to(_pad_to(x, jp, 1), bo, 0)
             for x in (queue, vol_left, budget, backlog_cap)]
     rates_p = _pad_to(_pad_to(rates, jp, 2), bo, 1)
     cap = _pad_to(cap_tick.reshape(-1), bo, 0)
     q, v, s = fleet_window_pallas(*args, rates_p, cap,
-                                  block_o=bo, interpret=interpret)
+                                  block_o=bo,
+                                  vmem_limit_bytes=_vmem_limit_bytes(
+                                      bo, jp, w + 10),
+                                  interpret=interpret)
     return q[:o, :j], v[:o, :j], s[:o, :j]
 
 

@@ -20,8 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
-
 
 def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, dskip_ref,
                 y_ref, state_ref, h_scr, *, chunk, n_chunks):
@@ -101,7 +99,7 @@ def ssd_pallas_bhcqp(x, dt, a, b, c, d_skip, *, chunk=128, interpret=False):
             jax.ShapeDtypeStruct((bsz, h, n, p_), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((n, p_), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, dt, a.reshape(h, 1), b, c, d_skip.reshape(h, 1))

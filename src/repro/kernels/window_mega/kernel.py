@@ -39,6 +39,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.policies import PolicyContext, WindowObs
 from repro.kernels.fleet_window.kernel import serve_window_block
@@ -136,7 +137,8 @@ def mega_round_block(policy, ctx_blk: PolicyContext, queue, vol_left, alloc,
 
     held: (served, demand, alloc) last-delivered observation rows;
     pstate: the policy-state pytree sliced to the block's rows;
-    rates: [W, O, J] (fault-scaled); cap2: [O, 1] effective per-tick rate;
+    rates: [W, O, J] (fault-scaled) -- the Pallas ref in the kernel body,
+    an array in the XLA fallback; cap2: [O, 1] effective per-tick rate;
     telem_col/up_col: optional [O, 1] fault columns.  ``ctx_blk`` must
     already carry the block's nodes/cap_w and ``alloc_backend="block"``
     (straight-line, Pallas-safe) or ``"block_cond"`` (runtime-specialized,
@@ -168,6 +170,7 @@ def mega_round_block(policy, ctx_blk: PolicyContext, queue, vol_left, alloc,
 def mega_window_pallas(policy, ctx: PolicyContext, queue, vol_left, alloc,
                        held, state_leaves, state_treedef, rates, backlog_cap,
                        cap_tick, telem_ok=None, up=None, *, block_o: int = 8,
+                       vmem_limit_bytes: int = None,
                        interpret: bool = False):
     """[O, J] fused control round.  rates: [W, O, J]; cap_tick: [O] (the
     effective, fault-scaled per-tick rate; ``ctx.cap_w`` must be its window
@@ -206,7 +209,7 @@ def mega_window_pallas(policy, ctx: PolicyContext, queue, vol_left, alloc,
         capw_b = next(it)[...]
         telem_b = next(it)[...] if has_faults else None
         up_b = next(it)[...] if has_faults else None
-        rates_b = next(it)[...]
+        rates_b = next(it)      # a ref: the serve loop loads one tick
         code = next(it)[0, 0] if has_code else None
         ctx_blk = ctx._replace(nodes=nodes_b, cap_w=capw_b[:, 0],
                                alloc_backend="block", control_code=code)
@@ -246,6 +249,8 @@ def mega_window_pallas(policy, ctx: PolicyContext, queue, vol_left, alloc,
         out_specs=out_specs,
         out_shape=out_shape,
         input_output_aliases=aliases,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=vmem_limit_bytes),
         interpret=interpret,
     )(*args)
     queue, vol_left, served, demand, obs_s, obs_d, obs_a = out[:7]

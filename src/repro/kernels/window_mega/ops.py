@@ -14,6 +14,7 @@ from repro.kernels.dispatch import block_rows as _block_rows
 from repro.kernels.dispatch import on_tpu as _on_tpu
 from repro.kernels.dispatch import pad_lanes as _pad_lanes
 from repro.kernels.dispatch import pad_to as _pad_to
+from repro.kernels.dispatch import vmem_limit_bytes as _vmem_limit_bytes
 from repro.kernels.window_mega.kernel import (
     mega_round_block,
     mega_window_pallas,
@@ -50,7 +51,7 @@ def _mega_round_xla(policy, ctx, cap_tick, backlog_cap, queue, vol_left,
     o, j = queue.shape
     w = rates_w.shape[0]
     leaves, treedef = _flatten_state(pstate, o)
-    bo = _block_rows(o, _pad_lanes(j), _live_rows(len(leaves), w))
+    bo = _block_rows(o)
     has_faults = telem_ok is not None
 
     row_arrays = [queue, vol_left, alloc, *held, *leaves,
@@ -145,7 +146,8 @@ def mega_window_round(policy, ctx, cap_tick, backlog_cap, queue, vol_left,
                 f"[O, J] rows; got a leaf of shape {leaf.shape} "
                 f"(expected {(o, j)})")
     jp = _pad_lanes(j)
-    bo = _block_rows(o, jp, _live_rows(len(leaves), w))
+    bo = _block_rows(o)
+    vmem = _vmem_limit_bytes(bo, jp, _live_rows(len(leaves), w))
 
     def pad(a):
         return _pad_to(_pad_to(a, jp, 1), bo, 0)
@@ -162,7 +164,7 @@ def mega_window_round(policy, ctx, cap_tick, backlog_cap, queue, vol_left,
         _pad_to(jnp.reshape(cap_tick, (o,)), bo, 0),
         telem_ok=None if telem_ok is None else pad_col(telem_ok),
         up=None if up is None else pad_col(up),
-        block_o=bo, interpret=interpret)
+        block_o=bo, vmem_limit_bytes=vmem, interpret=interpret)
     unpad = lambda a: a[:o, :j]
     pstate = jax.tree.unflatten(treedef, [unpad(x) for x in out[7]])
     return (*(unpad(x) for x in out[:7]), pstate, unpad(out[8]))

@@ -187,7 +187,10 @@ class FleetService:
         # the runtime honours it; semantics are identical either way.
         donate = (4,) if jax.default_backend() in ("tpu", "gpu") else ()
         self._step = jax.jit(step_fn, donate_argnums=donate)
-        self._carry = init_carry(cfg, self._policy, self._ctx(), volume)
+        # a donated carry needs one buffer per leaf, and the initializers
+        # may hand several zero-filled leaves one shared array
+        self._carry = jax.tree.map(
+            jnp.copy, init_carry(cfg, self._policy, self._ctx(), volume))
 
     def _ctx(self) -> PolicyContext:
         return PolicyContext(

@@ -512,7 +512,6 @@ def _run_windows_sharded(cfg: FleetConfig, policy: ControlPolicy, nodes,
     fault injection adds **no** mesh crossings and the bitwise guarantee
     extends to faulted runs (``tests/test_faults.py``).
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.launch.mesh import ost_mesh
@@ -548,8 +547,8 @@ def _run_windows_sharded(cfg: FleetConfig, policy: ControlPolicy, nodes,
         outs_specs = telemetry.stats_pspecs("ost")
     else:
         outs_specs = WindowOut(*(P(None, "ost", None),) * 4)
-    run = shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
-                    out_specs=(oj, outs_specs), check_rep=False)
+    run = jax.shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
+                        out_specs=(oj, outs_specs), check_vma=False)
     return run(*args)
 
 

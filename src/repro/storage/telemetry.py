@@ -203,6 +203,24 @@ def bin_upper_edge(b) -> float:
         / NBINS))
 
 
+def row_sum(x: jnp.ndarray) -> jnp.ndarray:
+    """Sum over the last axis in a fixed pairwise order.
+
+    XLA tiles a reduction to fit the fusion it lands in, and on a TPU that
+    tiling sets the order of the partial sums: one ``jnp.sum`` rounds
+    differently inside the offline window scan, in the online step, and on
+    a sharded row slice.  A halving tree of elementwise adds (zero-padded
+    to a power of two, which adds nothing) has one result per element
+    whatever the fusion, so the same-program identities stay bitwise."""
+    n = x.shape[-1]
+    width = 1 << (n - 1).bit_length()
+    x = jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, width - n)])
+    while x.shape[-1] > 1:
+        half = x.shape[-1] // 2
+        x = x[..., :half] + x[..., half:]
+    return x[..., 0]
+
+
 def update_stats(stats: StreamStats, served_w, demand, alloc, cap_w,
                  axis_name: Optional[str] = None,
                  faults_w=None) -> StreamStats:
@@ -226,8 +244,9 @@ def update_stats(stats: StreamStats, served_w, demand, alloc, cap_w,
     run's stats are bitwise those of the pre-fault engine.
     """
     n_ost = served_w.shape[0]
-    util_o = jnp.sum(served_w, axis=-1) / jnp.maximum(cap_w, 1e-12)
-    busy_osts = jnp.sum((jnp.sum(served_w, axis=-1) > 0).astype(jnp.int32))
+    served_o = row_sum(served_w)
+    util_o = served_o / jnp.maximum(cap_w, 1e-12)
+    busy_osts = jnp.sum((served_o > 0).astype(jnp.int32))
     if axis_name is not None:
         busy_osts = jax.lax.psum(busy_osts, axis_name)
     busy = busy_osts > 0
@@ -247,10 +266,9 @@ def update_stats(stats: StreamStats, served_w, demand, alloc, cap_w,
     alloc_sumsq, c_alloc_sumsq = _kahan(
         stats.alloc_sumsq, c.alloc_sumsq, alloc_f * alloc_f)
     util_sum, c_util_sum = _kahan(stats.util_sum, c.util_sum, util_o)
-    lag_sum, c_lag_sum = _kahan(stats.lag_sum, c.lag_sum,
-                                jnp.sum(lag, axis=-1))
+    lag_sum, c_lag_sum = _kahan(stats.lag_sum, c.lag_sum, row_sum(lag))
     lag_sumsq, c_lag_sumsq = _kahan(
-        stats.lag_sumsq, c.lag_sumsq, jnp.sum(lag * lag, axis=-1))
+        stats.lag_sumsq, c.lag_sumsq, row_sum(lag * lag))
     lag_hist, c_lag_hist = _kahan(stats.lag_hist, c.lag_hist, window_hist)
     down_windows, droop_windows, obs_lost = (
         stats.down_windows, stats.droop_windows, stats.obs_lost)

@@ -248,7 +248,6 @@ def simulate_tenants(
             'the single-fleet engine\'s -- fleet_shard with '
             "mesh_shape=(1, n_devices) shards the ost axis only)")
 
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.launch.mesh import fleet_ost_mesh
@@ -290,8 +289,8 @@ def simulate_tenants(
         return jax.vmap(functools.partial(body, "ost"),
                         in_axes=local_axes, axis_size=n_f // f_dev)(*xs)
 
-    run = shard_map(sharded_body, mesh=mesh, in_specs=tuple(in_specs),
-                    out_specs=(foj, outs_specs), check_rep=False)
+    run = jax.shard_map(sharded_body, mesh=mesh, in_specs=tuple(in_specs),
+                        out_specs=(foj, outs_specs), check_vma=False)
     return _package(cfg, *run(*args))
 
 

@@ -46,16 +46,24 @@ def test_capacity_sweep(cap):
 
 def test_block_o_stays_wide_at_fleet_scale():
     """O(J)-memory selection: the shared dispatcher keeps 8-row blocks out
-    to J=4096 (and beyond), where the old [block_o, J, J] rank matrix
+    to J=16384 (and beyond), where the old [block_o, J, J] rank matrix
     forced block_o=1 by J~1448 and could not fit J=4096 at any block size.
     It also never blocks wider than the (possibly sharded-local) row count,
     so a ``partition="ost_shard"`` shard dispatches exactly its own rows."""
-    from repro.kernels.dispatch import block_rows
-    assert block_rows(8, 128, ops._LIVE_ROWS) == 8
-    assert block_rows(8, 1536, ops._LIVE_ROWS) == 8
-    assert block_rows(8, 4096, ops._LIVE_ROWS) >= 4
-    assert block_rows(1, 128, ops._LIVE_ROWS) == 1
-    assert block_rows(2, 4096, ops._LIVE_ROWS) == 2
+    from repro.kernels.dispatch import (
+        DEFAULT_SCOPED_VMEM,
+        block_rows,
+        vmem_limit_bytes,
+    )
+    assert block_rows(8) == 8
+    assert block_rows(256) == 8
+    assert block_rows(1) == 1
+    assert block_rows(2) == 2
+    # the allocator's working set fits Mosaic's default scoped VMEM at
+    # 8 rows out to J=8192; J=16384 raises the limit, not the block
+    for j in (128, 1536, 4096, 8192):
+        assert vmem_limit_bytes(8, j, ops._LIVE_ROWS) == DEFAULT_SCOPED_VMEM
+    assert vmem_limit_bytes(8, 16384, ops._LIVE_ROWS) > DEFAULT_SCOPED_VMEM
 
 
 @pytest.mark.slow

@@ -57,6 +57,20 @@ def assert_results_bitwise(offline, online, telemetry_mode):
                                       np.asarray(online.queue_final))
 
 
+@pytest.mark.parametrize("policy", list_policies() + ["coded"])
+def test_initial_carry_leaves_are_distinct_buffers(policy):
+    """The online step donates its carry on an accelerator, and donating
+    one buffer twice is refused there; every leaf of the fresh carry must
+    own its buffer (the zero-filled policy and stats leaves once shared
+    one)."""
+    nodes, _, volume, cap, backlog = small_fleet()
+    cfg = FleetConfig(control=policy, telemetry="streaming")
+    code = FLEET_CONTROL_CODES["adaptbf"] if policy == "coded" else None
+    svc = FleetService(cfg, nodes, volume, cap, backlog, control_code=code)
+    leaves = jax.tree.leaves(svc.carry)
+    assert len({x.unsafe_buffer_pointer() for x in leaves}) == len(leaves)
+
+
 @pytest.mark.parametrize("telemetry_mode", ["trajectory", "streaming"])
 @pytest.mark.parametrize("policy", list_policies())
 def test_online_matches_offline_bitwise(policy, telemetry_mode):

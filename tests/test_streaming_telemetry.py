@@ -234,3 +234,20 @@ def test_utilization_single_definition_and_reexport():
     np.testing.assert_array_equal(a, b)
     np.testing.assert_array_equal(a, c)
     assert a.shape == (np.asarray(res.served).shape[0],)
+
+
+@pytest.mark.parametrize("n", [1, 2, 3, 128, 1000, 4096])
+def test_row_sum_is_a_fixed_pairwise_tree(n):
+    """The per-OST sums of the streaming fold (utilization, backlog growth)
+    use one summation order whatever fusion XLA puts them in: a halving
+    tree over the zero-padded row, reproduced here in numpy bit for bit."""
+    from repro.storage.telemetry import row_sum
+    x = np.random.default_rng(n).random((5, n)).astype(np.float32) * 37.0
+    width = 1 << (n - 1).bit_length()
+    tree = np.pad(x, [(0, 0), (0, width - n)])
+    while tree.shape[-1] > 1:
+        half = tree.shape[-1] // 2
+        tree = tree[:, :half] + tree[:, half:]
+    got = np.asarray(row_sum(jnp.asarray(x)))
+    assert got.tobytes() == tree[:, 0].tobytes()
+    np.testing.assert_allclose(got, x.sum(-1, dtype=np.float64), rtol=1e-5)
