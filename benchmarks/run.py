@@ -1,5 +1,4 @@
-"""Benchmark harness: one entry per paper table/figure plus the
-window-engine roofline (scan vs fused vs mega).  Prints
+"""Benchmark harness: one entry per paper table/figure.  Prints
 ``name,us_per_call,derived`` CSV rows followed by the detailed JSON per
 benchmark."""
 from __future__ import annotations
@@ -12,9 +11,7 @@ import time
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 import fleet_sweep
-import jax
 import paper_figures
-import roofline_report
 
 from repro.launch.compile_cache import use_compile_cache
 
@@ -44,15 +41,6 @@ def main() -> None:
     print()
     print("=== details ===")
     print(json.dumps(details, indent=2, default=float))
-    print()
-    print("## window-engine roofline (small cell; full grid: "
-          "benchmarks/roofline_report.py --out BENCH_roofline.json)")
-    kind = jax.devices()[0].device_kind
-    if kind not in roofline_report.DEVICE_PEAKS:
-        print(f"not measured: no published peaks for device kind {kind!r}")
-        return
-    roof = roofline_report.sweep(shapes=((8, 128),), n_windows=2)
-    print(json.dumps(roof["cells"], indent=2, default=float))
 
 
 if __name__ == "__main__":

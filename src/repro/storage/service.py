@@ -23,6 +23,11 @@ at a time and advances the *same* ``window_step`` the offline scan uses
   through ``repro/checkpoint``; ``restore()`` resumes bitwise from any
   saved window (save -> kill -> restore == the uninterrupted run).
 
+Each round carries three profiler spans, ``fleet.fetch``, ``fleet.copy``
+(the host side of the window's copy to the device) and ``fleet.dispatch``
+(the call of the jitted step): ``TraceAnnotation``s on the clock of the
+device planes, which record nothing without a profiler session.
+
 The carry's pytree *paths* are the checkpoint naming contract: leaves are
 saved keyed by ``jax.tree_util.keystr`` paths (``.queue``,
 ``.stats.served_sum``, ...), so the ``WindowCarry``/``StreamStats`` field
@@ -37,6 +42,7 @@ from typing import Callable, NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.policies import PolicyContext
 from repro.storage import telemetry
@@ -220,7 +226,8 @@ class FleetService:
         row that takes a previously-up OST down triggers ``save()``
         *before* the step, so restore replays from the disturbance.
         """
-        rates_w = jnp.asarray(rates_w, jnp.float32)
+        with TraceAnnotation("fleet.copy"):
+            rates_w = jnp.asarray(rates_w, jnp.float32)
         if rates_w.shape != (self.cfg.window_ticks, self.n_ost, self.n_jobs):
             raise ValueError(
                 f"rates_w must be [window_ticks={self.cfg.window_ticks}, "
@@ -242,9 +249,10 @@ class FleetService:
             self._up_prev = up_now
         else:
             self._up_prev = np.ones(self.n_ost, bool)
-        self._carry, out = self._step(
-            self._nodes, self._cap_tick, self._backlog_cap,
-            self._control_code, self._carry, rates_w, faults_w)
+        with TraceAnnotation("fleet.dispatch"):
+            self._carry, out = self._step(
+                self._nodes, self._cap_tick, self._backlog_cap,
+                self._control_code, self._carry, rates_w, faults_w)
         return out
 
     def run(self, rates, n_windows: Optional[int] = None,
@@ -331,7 +339,8 @@ class FleetService:
         while rates_w is None and attempts <= retries:
             try:
                 attempts += 1
-                rates_w = fetch()
+                with TraceAnnotation("fleet.fetch"):
+                    rates_w = fetch()
             except Exception:
                 rates_w = None
             if rates_w is not None:

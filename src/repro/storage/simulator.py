@@ -393,32 +393,42 @@ def window_step(cfg: FleetConfig, policy: ControlPolicy, ctx: PolicyContext,
             telem_ok=None if faults_w is None else faults_w.telem_ok,
             up=None if faults_w is None else faults_w.up)
     else:
-        budget0 = policy.gate(carry.alloc, ctx_w)
-        queue, vol_left, served_w = _serve_window(
-            cfg, carry.queue, carry.vol_left, budget0, rates_w, backlog_cap,
-            cap_tick_w)
-        demand = served_w + queue
-        if faults_w is None:
-            obs_served, obs_demand, obs_alloc = served_w, demand, carry.alloc
+        # phase scopes: HLO metadata only (the compiled program is the
+        # same), by which a profiler trace charges device time to a phase
+        # (bench/lib/phases.py); the telemetry scope below is the third
+        with jax.named_scope("fleet.serve"):
+            budget0 = policy.gate(carry.alloc, ctx_w)
+            queue, vol_left, served_w = _serve_window(
+                cfg, carry.queue, carry.vol_left, budget0, rates_w,
+                backlog_cap, cap_tick_w)
+            demand = served_w + queue
+        with jax.named_scope("fleet.allocate"):
+            if faults_w is None:
+                obs_served, obs_demand, obs_alloc = (served_w, demand,
+                                                     carry.alloc)
+            else:
+                delivered = faults_w.telem_ok[:, None] > 0
+                obs_served = jnp.where(delivered, served_w,
+                                       carry.held.served)
+                obs_demand = jnp.where(delivered, demand, carry.held.demand)
+                obs_alloc = jnp.where(delivered, carry.alloc,
+                                      carry.held.alloc)
+            pstate, alloc_next = policy.step(
+                carry.policy_state,
+                WindowObs(served=obs_served, demand=obs_demand,
+                          alloc=obs_alloc, up=up_col), ctx_w)
+    with jax.named_scope("fleet.telemetry"):
+        if cfg.telemetry == "streaming":
+            stats = telemetry.update_stats(carry.stats, served_w, demand,
+                                           carry.alloc, ctx_w.cap_w,
+                                           axis_name=axis_name,
+                                           faults_w=faults_w)
+            out = None
         else:
-            delivered = faults_w.telem_ok[:, None] > 0
-            obs_served = jnp.where(delivered, served_w, carry.held.served)
-            obs_demand = jnp.where(delivered, demand, carry.held.demand)
-            obs_alloc = jnp.where(delivered, carry.alloc, carry.held.alloc)
-        pstate, alloc_next = policy.step(
-            carry.policy_state,
-            WindowObs(served=obs_served, demand=obs_demand, alloc=obs_alloc,
-                      up=up_col), ctx_w)
-    if cfg.telemetry == "streaming":
-        stats = telemetry.update_stats(carry.stats, served_w, demand,
-                                       carry.alloc, ctx_w.cap_w,
-                                       axis_name=axis_name,
-                                       faults_w=faults_w)
-        out = None
-    else:
-        stats = carry.stats
-        out = WindowOut(served=served_w, demand=demand, alloc=carry.alloc,
-                        record=policy.record(pstate, ctx_w))
+            stats = carry.stats
+            out = WindowOut(served=served_w, demand=demand,
+                            alloc=carry.alloc,
+                            record=policy.record(pstate, ctx_w))
     return WindowCarry(window=carry.window + 1, queue=queue,
                        vol_left=vol_left, policy_state=pstate,
                        alloc=alloc_next, stats=stats,
