@@ -22,6 +22,12 @@ sharding by summing int32 busy-OST counts with ``lax.psum`` -- integer
 addition is associative, so the flag (and the int32 ``busy_windows``
 counter) cannot drift with device count.
 
+The backlog histogram is counted per platform (``count_bins``): a
+scatter-add where that is fast (CPU, GPU), and on a TPU, which serialises
+a scatter's colliding updates, a one-hot contraction over the job axis on
+the MXU.  The counts are small integers, exact in f32 whatever the order
+of additions, so both give the same histogram bit for bit.
+
 Accuracy at extreme horizons: JAX runs f32 by default, and a plain f32
 running sum silently drops increments once the total passes 2^24 (a job
 served 200 RPCs/window stalls after ~10^5 windows).  Every floating-point
@@ -195,6 +201,50 @@ def lag_bin(lag: jnp.ndarray) -> jnp.ndarray:
     return jnp.clip(jnp.floor(f).astype(jnp.int32), 0, NBINS - 1)
 
 
+_LO_BINS = 16          # a bin index splits as b = _LO_BINS * hi + lo
+
+
+def _count_bins_scatter(bins: jnp.ndarray) -> jnp.ndarray:
+    """[O, J] bin indices -> [O, NBINS] f32 counts by scatter-add."""
+    n_ost = bins.shape[0]
+    return jnp.zeros((n_ost, NBINS), jnp.float32).at[
+        jnp.arange(n_ost)[:, None], bins].add(1.0)
+
+
+def _count_bins_dot(bins: jnp.ndarray) -> jnp.ndarray:
+    """[O, J] bin indices -> [O, NBINS] f32 counts by a factored one-hot
+    contraction: ``b = 16 hi + lo``, and ``count[o, hi, lo]`` is the dot
+    over J of the 0/1 one-hots ``[O, J, 8]`` and ``[O, J, 16]`` (bf16
+    operands, f32 accumulation, on the MXU).  XLA fuses the one-hots into
+    the dot, so no ``[O, J, 8]``/``[O, J, 16]`` buffer is written."""
+    hi = jnp.arange(NBINS // _LO_BINS, dtype=bins.dtype)
+    lo = jnp.arange(_LO_BINS, dtype=bins.dtype)
+    hot_hi = (bins[:, :, None] // _LO_BINS == hi).astype(jnp.bfloat16)
+    hot_lo = (bins[:, :, None] % _LO_BINS == lo).astype(jnp.bfloat16)
+    counts = jnp.einsum("ojh,ojl->ohl", hot_hi, hot_lo,
+                        preferred_element_type=jnp.float32)
+    return counts.reshape(bins.shape[0], NBINS)
+
+
+def count_bins(bins: jnp.ndarray) -> jnp.ndarray:
+    """Histogram of each row of ``[O, J]`` bin indices in ``[0, NBINS)``:
+    ``[O, NBINS]`` f32 counts, the same on every platform, bit for bit.
+
+    The formulation follows the platform the program lowers for.  On a TPU
+    a scatter's colliding updates are serialised (4096 jobs into 128 bins
+    a row: ~8.9 ms a window at (248, 4096) on a v5e), so the TPU counts by
+    ``_count_bins_dot``'s one-hot contraction (~0.2 ms there).  Elsewhere
+    the scatter-add is the fast one, and stays: on an 8-core Xeon CPU the
+    whole ``update_stats`` fold takes 12-14 ms a window at (248, 4096)
+    with the scatter and 54-70 ms with the contraction, 1.2-2.0 against
+    2.6 ms at (64, 1024), and 0.40 against 0.60-0.66 ms at (16, 256).
+    Both are exact: a count is an integer no larger than J, far below
+    2^24, so any order of f32 additions gives it; the one-hots are exact
+    in bf16."""
+    return jax.lax.platform_dependent(
+        bins, tpu=_count_bins_dot, default=_count_bins_scatter)
+
+
 def bin_upper_edge(b) -> float:
     """Upper edge (RPCs) of histogram bin ``b``."""
     import numpy as np
@@ -238,12 +288,16 @@ def update_stats(stats: StreamStats, served_w, demand, alloc, cap_w,
     ``psum``-med across the mesh so the flag matches the unsharded run bit
     for bit (integer addition cannot reorder-drift).
 
+    The window's backlog histogram is ``count_bins(lag_bin(lag))``: a
+    scatter-add off the TPU, a one-hot contraction on it (a TPU serialises
+    the scatter's colliding updates).  Its counts are integers no larger
+    than J, exact in f32, so the carry is bitwise the same on either path.
+
     ``faults_w`` (optional ``faults.FaultPlan`` row, [O] leaves) advances
     the row-local fault counters: windows down, windows up-but-degraded,
     observations lost.  ``None`` leaves them untouched -- a fault-free
     run's stats are bitwise those of the pre-fault engine.
     """
-    n_ost = served_w.shape[0]
     served_o = row_sum(served_w)
     util_o = served_o / jnp.maximum(cap_w, 1e-12)
     busy_osts = jnp.sum((served_o > 0).astype(jnp.int32))
@@ -253,8 +307,7 @@ def update_stats(stats: StreamStats, served_w, demand, alloc, cap_w,
     lag = demand - served_w
     ruled = jnp.isfinite(alloc)
     alloc_f = jnp.where(ruled, alloc, 0.0)
-    window_hist = jnp.zeros((n_ost, NBINS), jnp.float32).at[
-        jnp.arange(n_ost)[:, None], lag_bin(lag)].add(1.0)
+    window_hist = count_bins(lag_bin(lag))
     c = stats.comp
     served_sum, c_served_sum = _kahan(stats.served_sum, c.served_sum, served_w)
     served_sumsq, c_served_sumsq = _kahan(

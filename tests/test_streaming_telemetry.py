@@ -251,3 +251,59 @@ def test_row_sum_is_a_fixed_pairwise_tree(n):
     got = np.asarray(row_sum(jnp.asarray(x)))
     assert got.tobytes() == tree[:, 0].tobytes()
     np.testing.assert_allclose(got, x.sum(-1, dtype=np.float64), rtol=1e-5)
+
+
+def _lags(case):
+    """Backlog values for the histogram cases, [..., O, J] float32."""
+    rng = np.random.default_rng(14)
+    if case == "random":
+        return rng.lognormal(2.0, 3.0, (8, 4096)).astype(np.float32)
+    if case == "edges":
+        # every bin edge 10^(-2 + 8k/128), zeros, negatives, past 10^6
+        edges = 10.0 ** (-2.0 + np.arange(129) * 8.0 / 128.0)
+        row = np.concatenate([edges, np.zeros(40), -edges[:40],
+                              [1e6, 2e6, 1e9, 3.4e38, -3.4e38]])
+        return np.stack([row, row[::-1], np.roll(row, 7)]).astype(np.float32)
+    if case == "one_ost":
+        return rng.lognormal(2.0, 3.0, (1, 4096)).astype(np.float32)
+    assert case == "tenants"
+    return rng.lognormal(2.0, 3.0, (5, 8, 512)).astype(np.float32)
+
+
+@pytest.mark.parametrize("case", ["random", "edges", "one_ost", "tenants"])
+def test_histogram_counts_agree_bitwise_across_formulations(case):
+    """The TPU's one-hot contraction and the scatter-add count the same
+    histogram as ``np.add.at``, bit for bit, at the fleet's shapes, at
+    exact bin edges and out-of-range backlogs, at O = 1 and under a vmap
+    over a tenant axis."""
+    import jax
+    from repro.storage import telemetry
+
+    lag = _lags(case)
+    bins = telemetry.lag_bin(jnp.asarray(lag))
+    want = np.zeros(lag.shape[:-1] + (telemetry.NBINS,), np.float32)
+    lead = np.indices(lag.shape[:-1])
+    np.add.at(want, (*[i[..., None] for i in lead], np.asarray(bins)), 1.0)
+    assert want.sum() == lag.size
+    for count in (telemetry._count_bins_dot, telemetry._count_bins_scatter,
+                  telemetry.count_bins):
+        f = jax.jit(jax.vmap(count) if lag.ndim == 3 else count)
+        got = np.asarray(f(bins))
+        assert got.dtype == np.float32
+        assert got.tobytes() == want.tobytes(), count.__name__
+
+
+def test_cpu_fold_keeps_the_scatter():
+    """Off the TPU the streaming fold counts its histogram by scatter-add,
+    and only the TPU's branch holds the one-hot contraction."""
+    import jax
+    from repro.storage import telemetry
+
+    stats = telemetry.init_stats(8, 256)
+    x = jnp.ones((8, 256), jnp.float32)
+    cap = jnp.full((8,), 10.0, jnp.float32)
+    text = jax.jit(
+        lambda s, a: telemetry.update_stats(s, a, 2 * a, a, cap)
+    ).lower(stats, x).compile().as_text()
+    assert "scatter" in text
+    assert " dot(" not in text

@@ -7,12 +7,15 @@ scoped VMEM than the kernel asked for.  Each test here lowers one kernel
 with ``interpret=False`` at the engine's documented peak shape
 (O, J, W) = (256, 4096, 10) for one chip of a described ``v5e:2x2``
 topology, compiles it, and checks that the program holds the kernel
-(``tpu_custom_call``).  Nothing runs.
+(``tpu_custom_call``).  The streaming fold, which has a TPU-only way of
+counting its histogram, is compiled the same way.  Nothing runs.
 
 The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU library, so a worker that is not
 given this file must not touch it.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -22,6 +25,7 @@ from repro.core.policies import CodedPolicy, PolicyContext, get_policy
 from repro.kernels.adaptbf_alloc.ops import fleet_alloc
 from repro.kernels.fleet_window.ops import fleet_window_serve
 from repro.kernels.window_mega import ops as mega_ops
+from repro.storage import telemetry
 
 O, J, W = 256, 4096, 10
 
@@ -110,3 +114,47 @@ def test_window_mega_compiles_at_j8192_with_one_row_tile(one_chip):
     assert dispatch.block_rows(O) == 8
     assert "tpu_custom_call" in _mega_text(one_chip, 8192)
 
+
+def _unfused_shapes(text: str):
+    """Result shapes of the instructions outside fusion bodies: the
+    buffers the program writes to memory."""
+    fused = set(re.findall(r"calls=(%[\w.-]+)", text))
+    shapes, keep = [], False
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?(%[\w.-]+) .*\{$", line)
+        if head:
+            keep = head.group(1) not in fused
+        elif keep and " = " in line:
+            result = line.split(" = ", 1)[1]
+            result = result[:re.search(r" [a-z][\w-]*\(", result).start()]
+            shapes += re.findall(r"\w+\[[\d,]*\]", result)
+    return shapes
+
+
+@pytest.mark.parametrize("tenants", [None, 5])
+def test_streaming_fold_counts_without_a_scatter_on_v5e(one_chip, tenants):
+    """On a TPU the fold's backlog histogram is the one-hot contraction:
+    no scatter, and its ``[O, J, 8]``/``[O, J, 16]`` one-hots (or an
+    ``[O, J, NBINS]`` one) fused away, at the benchmark's (248, 4096),
+    alone and under a vmap over five tenants."""
+    o, j = 248, 4096
+    lead = () if tenants is None else (tenants,)
+
+    def spec(x):
+        return jax.ShapeDtypeStruct(lead + x.shape, x.dtype,
+                                    sharding=one_chip)
+
+    stats = jax.tree.map(
+        spec, jax.eval_shape(lambda: telemetry.init_stats(o, j)))
+    oj = spec(jax.ShapeDtypeStruct((o, j), jnp.float32))
+    cap = spec(jax.ShapeDtypeStruct((o,), jnp.float32))
+    fold = telemetry.update_stats
+    if tenants is not None:
+        fold = jax.vmap(fold)
+    text = _compile_text(fold, stats, oj, oj, oj, cap)
+    assert not re.search(r" scatter\(", text)
+    shapes = _unfused_shapes(text)
+    assert any(s.endswith(f"{o},{j}]") for s in shapes)  # it sees buffers
+    one_hots = [s for s in shapes
+                if re.search(rf"{o},{j},(8|16|{telemetry.NBINS})\]", s)]
+    assert not one_hots
